@@ -51,9 +51,5 @@ def counters_of(fn: Callable, keys=COUNTER_KEYS) -> Dict[str, int]:
 
     tr = RunTrace(enabled=True)
     with activate(tr):
-        out = fn()
-        try:
-            jax.block_until_ready(out)
-        except Exception:
-            pass
+        jax.block_until_ready(fn())
     return {k: int(tr.counters[k]) for k in keys}

@@ -12,7 +12,8 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)
 
 from benchmarks import bench_constrained, bench_mr, bench_streaming
 from benchmarks.common import table
@@ -49,6 +50,8 @@ def main(argv=None) -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    print(f"compile cache: {enable_compile_cache(_ROOT)}")
     quick = not args.full
     t0 = time.time()
 

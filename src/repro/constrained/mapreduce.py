@@ -105,7 +105,7 @@ def mr_grouped_coreset(points, labels, m: Optional[int] = None,
     ``kprime="auto"`` probe the labelled input once on the host and compile
     the adaptive controller's decisions into every reducer as a static
     (block, rounds) schedule."""
-    from repro.compat import shard_map
+    from jax import shard_map
 
     from repro.core.distributed import _resolve_reducer_plan
 

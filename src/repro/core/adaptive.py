@@ -50,8 +50,9 @@ import numpy as np
 from repro.obs.trace import (count as _count, counting as _counting,
                              span as _span, sweep_bytes as _sweep_bytes)
 
-from .gmm import (_grouped_inblock, _make_grouped_sweep, pad_for_engine,
-                  mask_to_labels, schedule_sweep_counts, validate_schedule)
+from .gmm import (_grouped_inblock, _make_grouped_sweep, gather_rows,
+                  pad_for_engine, mask_to_labels, schedule_sweep_counts,
+                  validate_schedule)
 from .metrics import get_metric
 
 
@@ -340,7 +341,7 @@ def _resume_impl(points, labels, min_dist, idx, start, end, m: int, kcap: int,
     def body(r, state):
         md, idx, tcol = state
         prev = jax.lax.dynamic_slice(idx, (0, r - 1), (m, 1))
-        md, cd, ci = sweep(md, points[prev])
+        md, cd, ci = sweep(md, gather_rows(points, prev))
         idx = jax.lax.dynamic_update_slice(idx, ci, (0, r))
         tcol = jax.lax.dynamic_update_slice(tcol, cd[:, :1].T, (r, 0))
         return md, idx, tcol

@@ -15,9 +15,10 @@ The recursive scheme (Thm 8) is a 2-level reduction: within-pod over the
 
 Two execution paths:
  * ``mesh`` path — real shard_map for the production mesh / dry-run;
- * ``simulate_reducers`` — vmap over ℓ logical reducers on one device, used by
-   the CPU benchmark suite to reproduce the paper's parallelism sweeps
-   (Fig 4/5) without hardware.
+ * ``simulate_reducers`` — ℓ logical reducers dispatched in turn on one
+   device, each running the mesh path's per-device program, used by the
+   benchmark suite to reproduce the paper's parallelism sweeps (Fig 4/5)
+   without a mesh.
 """
 from __future__ import annotations
 
@@ -144,7 +145,7 @@ def mr_coreset(points, k: int, kprime, measure: str, mesh: Mesh,
     ``b="auto"`` / ``kprime="auto"`` run a host-side probe once and compile
     its decisions into every reducer as a static (block, rounds) schedule
     (``eps`` is the auto-k' accuracy target)."""
-    from repro.compat import shard_map
+    from jax import shard_map
 
     axes = tuple(data_axes)
     nshards = int(np.prod([mesh.shape[a] for a in axes]))
@@ -292,7 +293,7 @@ def mr_coreset_recursive(points, k: int, kprime, measure: str, mesh: Mesh,
                          tau=None, cliff=None):
     """Thm 8: two-level reduction — per-device core-sets over ``data``,
     re-contracted over ``pod`` (requires a ('pod','data',...) mesh)."""
-    from repro.compat import shard_map
+    from jax import shard_map
 
     if "pod" not in mesh.axis_names:
         raise ValueError("recursive scheme expects a 'pod' axis")
@@ -375,65 +376,69 @@ def partition_shards(points, num_reducers: int, *, partition: str = "contiguous"
 
 @functools.partial(jax.jit, static_argnames=("k", "kprime", "metric", "mode",
                                              "b", "chunk", "schedule"))
-def _sim_round1(shards, k: int, kprime: int, metric: str, mode: str,
-                b: int = 1, chunk: int = 0, schedule=None):
+def _sim_reducer(shard, k: int, kprime: int, metric: str, mode: str,
+                 b: int = 1, chunk: int = 0, schedule=None):
+    """Round 1 of one simulated reducer on its (per, d) shard: the same
+    unbatched program a device of the mesh path runs.  (A vmap over the
+    reducers batches the distance matmul, which a TPU rounds differently
+    from the per-device one, so the two paths' core-sets would part.)
+    Returns (points, valid, radius) with a leading reducer axis of 1."""
     if mode == "plain":
-        def one(s):
-            pts, radius = _local_coreset_plain(s, kprime, metric, False,
-                                               b, chunk, schedule)
-            return pts, jnp.ones((kprime,), bool), radius
+        pts, radius = _local_coreset_plain(shard, kprime, metric, False,
+                                           b, chunk, schedule)
+        out = pts, jnp.ones((kprime,), bool), radius
     elif mode == "ext":
-        def one(s):
-            ext = _gmm_ext(s, k, kprime, metric=metric, b=b, chunk=chunk,
-                           schedule=schedule)
-            return (s[ext.delegate_idx.reshape(-1)],
-                    ext.delegate_valid.reshape(-1), ext.radius)
+        ext = _gmm_ext(shard, k, kprime, metric=metric, b=b, chunk=chunk,
+                       schedule=schedule)
+        out = (shard[ext.delegate_idx.reshape(-1)],
+               ext.delegate_valid.reshape(-1), ext.radius)
     else:  # gen
-        def one(s):
-            g = _gmm_gen(s, k, kprime, metric=metric, b=b, chunk=chunk,
-                         schedule=schedule)
-            return g.points, g.multiplicity > 0, g.radius
-
-    return jax.vmap(one)(shards)
+        g = _gmm_gen(shard, k, kprime, metric=metric, b=b, chunk=chunk,
+                     schedule=schedule)
+        out = g.points, g.multiplicity > 0, g.radius
+    return tuple(x[None] for x in out)
 
 
-def _sim_round1_detail(shards, k: int, kprime: int, metric: str, mode: str,
-                       b: int = 1, chunk: int = 0, schedule=None):
-    """Per-reducer observability path (``ExecutionSpec(trace="reducers")``):
-    the same jitted body as ``_sim_round1``, dispatched once per reducer on
-    a leading axis of 1 instead of one vmapped launch, so every reducer gets
-    a real span with its own wall-clock.  The per-reducer times feed
-    ``distributed.fault_tolerance.StragglerPolicy`` (warmup-aware: reducer 0
-    carries the jit compile) and flagged reducers land in the trace extras
-    as ``mr_stragglers``.  Slower than the vmapped launch by construction —
-    this is an observability mode, not a production path."""
+def _stack_reducers(outs):
+    return tuple(jnp.concatenate([o[j] for o in outs], axis=0)
+                 for j in range(3))
+
+
+def _sim_round1(shards, args, *, detail: bool = False):
+    """Round 1 of every simulated reducer, one ``_sim_reducer`` dispatch
+    each (``args``: its static arguments); returns the reducer-stacked
+    (points, valid, radius).
+
+    ``detail`` (``ExecutionSpec(trace="reducers")``) fences every reducer
+    in its own span, so each gets a real wall-clock.  The per-reducer times
+    feed ``distributed.fault_tolerance.StragglerPolicy`` (warmup-aware:
+    reducer 0 carries the jit compile) and flagged reducers land in the
+    trace extras as ``mr_stragglers``.  Slower than the pipelined dispatch
+    by construction — an observability mode, not a production path."""
     from repro.distributed.fault_tolerance import StragglerPolicy
 
     policy = StragglerPolicy(min_history=3)
     outs, stragglers = [], []
     for i in range(int(shards.shape[0])):
+        if not detail:
+            outs.append(_sim_reducer(shards[i], *args))
+            continue
         with _span(f"mr.reducer[{i}]", reducer=i) as sp:
-            out = jax.block_until_ready(_sim_round1(
-                shards[i:i + 1], k, kprime, metric, mode, b, chunk,
-                schedule))
-        _count("device_dispatches")
-        outs.append(out)
+            outs.append(jax.block_until_ready(_sim_reducer(shards[i], *args)))
         if sp is not None and policy.observe(sp.seconds):
             stragglers.append(i)
+    _count("device_dispatches", len(outs))
     tr = _obs_active()
-    if tr is not None:
+    if detail and tr is not None:
         tr.annotate(mr_stragglers=tuple(stragglers))
-    return tuple(jnp.concatenate([o[j] for o in outs], axis=0)
-                 for j in range(3))
+    return _stack_reducers(outs)
 
 
-def _sim_round1_resilient(shards, k: int, kprime: int, metric: str,
-                          mode: str, b, chunk, schedule, policy):
-    """Round 1 under a ``ResiliencePolicy``: the same jitted body as
-    ``_sim_round1``, dispatched once per reducer (the ``_sim_round1_detail``
-    pattern) so each reducer is an independently retryable unit.  Failed
+def _sim_round1_resilient(shards, args, policy):
+    """Round 1 under a ``ResiliencePolicy``: the reducers of
+    ``_sim_round1``, each an independently retryable unit.  Failed
     reducers (``on_failure="degrade"``) contribute an all-zeros block with
-    ``valid=False`` — the merged layout is identical to the vmapped launch,
+    ``valid=False`` — the merged layout is identical to the plain run,
     and the composable core-set property keeps the surviving union a valid
     core-set of the surviving shards.  Returns (pts, valid, radius, report).
     """
@@ -443,9 +448,7 @@ def _sim_round1_resilient(shards, k: int, kprime: int, metric: str,
 
     def run_one(i):
         with _span(f"mr.reducer[{i}]", reducer=i):
-            out = jax.block_until_ready(_sim_round1(
-                shards[i:i + 1], k, kprime, metric, mode, b, chunk,
-                schedule))
+            out = jax.block_until_ready(_sim_reducer(shards[i], *args))
         _count("device_dispatches")
         return out
 
@@ -457,9 +460,7 @@ def _sim_round1_resilient(shards, k: int, kprime: int, metric: str,
             f"{policy.on_failure!r}; nothing to merge")
     outs = [o if o is not None else jax.tree.map(jnp.zeros_like, ok[0])
             for o in outs]
-    merged = tuple(jnp.concatenate([o[j] for o in outs], axis=0)
-                   for j in range(3))
-    return merged + (report,)
+    return _stack_reducers(outs) + (report,)
 
 
 def _simulate_mr_impl(points, k: int, measure: str, *, num_reducers: int,
@@ -487,18 +488,16 @@ def _simulate_mr_impl(points, k: int, measure: str, *, num_reducers: int,
     if _counting():
         _count_round1(num_reducers, per_shard, d, kprime, b,
                       schedule, mode)
+    args = (k, kprime, metric, mode, b, chunk, schedule)
     report = None
     if resilience is not None:
         g_pts, g_valid, g_rad, report = _sim_round1_resilient(
-            shards, k, kprime, metric, mode, b, chunk, schedule, resilience)
+            shards, args, resilience)
     elif _reducer_detail():
-        g_pts, g_valid, g_rad = _sim_round1_detail(shards, k, kprime, metric,
-                                                   mode, b, chunk, schedule)
+        g_pts, g_valid, g_rad = _sim_round1(shards, args, detail=True)
     else:
         with _span("mr.round1", reducers=num_reducers, kprime=kprime):
-            g_pts, g_valid, g_rad = _sim_round1(shards, k, kprime, metric,
-                                                mode, b, chunk, schedule)
-            _count("device_dispatches")
+            g_pts, g_valid, g_rad = _sim_round1(shards, args)
             if _counting():
                 jax.block_until_ready(g_rad)
     flat_pts = g_pts.reshape(-1, d)
@@ -552,7 +551,8 @@ def simulate_mr(points, k: int, measure: str, *, num_reducers: int,
                 generalized: bool = False, partition: str = "contiguous",
                 seed: int = 0, b=1, chunk: int = 0, eps: float = 0.1,
                 tau=None, cliff=None):
-    """Simulate the ℓ-reducer 2-round MR run on one device (vmap over shards).
+    """Simulate the ℓ-reducer 2-round MR run on one device, one dispatch per
+    reducer.
 
     Legacy spelling of ``repro.diversify`` with ``ExecutionSpec(
     mode="mapreduce", num_reducers=...)`` — prefer the facade for new code.

@@ -46,12 +46,21 @@ def _host_counting(x) -> bool:
     """Counters fire only on real host-driver calls: a call made while
     tracing another jit (x is a Tracer) runs once per compile, not per
     execution, so counting there would be wrong."""
-    if not _counting():
-        return False
-    try:
-        return not isinstance(x, jax.core.Tracer)
-    except Exception:                                # pragma: no cover
-        return True
+    return _counting() and not isinstance(x, jax.core.Tracer)
+
+
+def gather_rows(points, idx):
+    """``points[idx]``, fetched as a gather of at least two rows.  On a TPU
+    an (n, 3) array is stored column-major (``T(4,128)``); a one-row fetch
+    inside a loop lowers to a dynamic-slice, for which XLA copies the whole
+    array row-major and padded to 128 lanes (32 GB at n=2^26).  A gather of
+    two or more rows reads it in place."""
+    idx = jnp.asarray(idx)
+    if idx.size > 1:
+        return points[idx]
+    flat = idx.reshape(-1)
+    rows = points[jnp.concatenate([flat, flat])][:1]
+    return rows.reshape(idx.shape + points.shape[1:])
 
 
 class GMMResult(NamedTuple):
@@ -91,7 +100,7 @@ def _gmm_impl(points, mask, start, k: int, metric_name: str, use_pallas: bool):
         min_dist, assign, idx, sel_dist, _ = state
         # distance from all points to the center chosen at step i-1; fused
         # running-min + masked argmax (one HBM sweep on the Pallas path)
-        center = points[idx[i - 1]]
+        center = gather_rows(points, idx[i - 1])
         new_dist, j, jmax = update_select(min_dist, center)
         assign = jnp.where(new_dist < min_dist, i - 1, assign)
         idx = idx.at[i].set(j, mode="drop")          # i == k write is dropped
@@ -248,12 +257,14 @@ def _grouped_inblock(points, metric_name: str, cand_d, cand_i, take: int):
     neg_inf = jnp.asarray(-jnp.inf, jnp.float32)
 
     def one(cd, ci):
+        pool = points[ci]                            # (p, d) candidates
+
         def pick(j, carry):
             cd, chosen, seld = carry
             s = jnp.argmax(cd)
             chosen = chosen.at[j].set(ci[s])
             seld = seld.at[j].set(cd[s])
-            dd = metric.point_to_set(points[ci], points[ci[s]])
+            dd = metric.point_to_set(pool, pool[s])
             cd = jnp.minimum(cd, dd).at[s].set(neg_inf)
             return cd, chosen, seld
 
@@ -377,7 +388,7 @@ def _schedule_select_impl(points, labels, starts, m: int, k: int, schedule,
                 md, idx, traj, bcd = state
                 prev = jax.lax.dynamic_slice(idx, (0, base + (t - 1) * b),
                                              (m, b))
-                md, cd, ci = sweep(md, points[prev])
+                md, cd, ci = sweep(md, gather_rows(points, prev))
                 si = sc_base + t - 1
                 traj = jax.lax.dynamic_update_slice(traj, cd[:, :1].T,
                                                     (si, 0))

@@ -34,11 +34,18 @@ def _sq_norms(x):
     return jnp.sum(x * x, axis=-1)
 
 
+def _mm(a, b):
+    # Full f32 cross terms: at the TPU's default precision an f32 matmul is
+    # rounded through bf16, and the norm expansion below turns that rounding
+    # into distance error.
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _sqeuclidean_pairwise(x, y):
     # ||x-y||^2 = ||x||^2 + ||y||^2 - 2 x.y   (MXU-friendly form)
     xx = _sq_norms(x)[:, None]
     yy = _sq_norms(y)[None, :]
-    xy = x @ y.T
+    xy = _mm(x, y.T)
     return jnp.maximum(xx + yy - 2.0 * xy, 0.0)
 
 
@@ -47,12 +54,12 @@ def _euclidean_pairwise(x, y):
 
 
 def _euclidean_p2s(x, c):
-    d2 = _sq_norms(x) + jnp.sum(c * c) - 2.0 * (x @ c)
+    d2 = _sq_norms(x) + jnp.sum(c * c) - 2.0 * _mm(x, c)
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 
 
 def _sqeuclidean_p2s(x, c):
-    d2 = _sq_norms(x) + jnp.sum(c * c) - 2.0 * (x @ c)
+    d2 = _sq_norms(x) + jnp.sum(c * c) - 2.0 * _mm(x, c)
     return jnp.maximum(d2, 0.0)
 
 
@@ -60,14 +67,14 @@ def _cosine_pairwise(x, y):
     # arccos of cosine similarity -- the paper's distance for musiXmatch (§7).
     xn = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-30)
     yn = y / jnp.maximum(jnp.linalg.norm(y, axis=-1, keepdims=True), 1e-30)
-    sim = jnp.clip(xn @ yn.T, -1.0, 1.0)
+    sim = jnp.clip(_mm(xn, yn.T), -1.0, 1.0)
     return jnp.arccos(sim)
 
 
 def _cosine_p2s(x, c):
     xn = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), 1e-30)
     cn = c / jnp.maximum(jnp.linalg.norm(c), 1e-30)
-    sim = jnp.clip(xn @ cn, -1.0, 1.0)
+    sim = jnp.clip(_mm(xn, cn), -1.0, 1.0)
     return jnp.arccos(sim)
 
 

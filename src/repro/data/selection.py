@@ -115,21 +115,38 @@ def select_diverse(embeddings: np.ndarray, k: int, *, measure="remote-edge",
 
 def _match_rows(pts: np.ndarray, sol: np.ndarray, k: int, *,
                 row_labels=None, sol_labels=None) -> np.ndarray:
-    """Map solution points back to distinct row indices (exact match by row).
+    """Map solution points back to distinct row indices.
 
-    With ``row_labels``/``sol_labels``, candidates are restricted to rows of
-    the solution point's own group (preserves quota feasibility).  Each pick
-    is a masked argmin — O(n) per solution point, no argsort."""
-    idx = []
-    taken = np.zeros(pts.shape[0], bool)
+    A solution point equal to an input row (of its own group, with
+    ``row_labels``/``sol_labels`` — preserves quota feasibility) maps to the
+    lowest-index such row not taken yet.  Every row equal to a solution
+    point shares its first coordinate, so one binary search per row over the
+    solution's first coordinates finds all candidates in a single pass.  A
+    solution point with no untaken exact match falls back to the nearest
+    untaken row (a masked argmin, O(n))."""
+    pts, sol = np.asarray(pts), np.asarray(sol)
     labels_np = None if row_labels is None else np.asarray(row_labels)
+    taken = np.zeros(pts.shape[0], bool)
+    first = np.sort(sol[:, 0])
+    col = pts[:, 0]
+    hit = first[np.minimum(np.searchsorted(first, col), len(first) - 1)]
+    cand = np.flatnonzero(hit == col)
+    cand_rows = pts[cand]
+    idx = []
     for t, s in enumerate(sol):
-        d = np.linalg.norm(pts - s[None, :], axis=1)
+        ok = np.all(cand_rows == s, axis=1) & ~taken[cand]
         if labels_np is not None:
-            d = np.where(labels_np == sol_labels[t], d, np.inf)
-        d[taken] = np.inf
-        j = int(np.argmin(d))
-        if np.isfinite(d[j]):
-            idx.append(j)
-            taken[j] = True
+            ok &= labels_np[cand] == sol_labels[t]
+        if ok.any():
+            j = int(cand[np.argmax(ok)])
+        else:
+            d = np.linalg.norm(pts - s[None, :], axis=1)
+            if labels_np is not None:
+                d = np.where(labels_np == sol_labels[t], d, np.inf)
+            d[taken] = np.inf
+            j = int(np.argmin(d))
+            if not np.isfinite(d[j]):
+                continue
+        idx.append(j)
+        taken[j] = True
     return np.asarray(idx[:k])

@@ -33,11 +33,29 @@ import time
 import zlib
 from typing import Any, Callable, List, Optional, Sequence, Tuple
 
+from jax.errors import JaxRuntimeError
+
 from repro.checkpoint import CheckpointManager
 
 
 class InjectedFailure(RuntimeError):
     pass
+
+
+# Runtime statuses that mean a device or peer went away mid-unit.  A
+# lowering, compile or out-of-memory error (RESOURCE_EXHAUSTED, INTERNAL,
+# INVALID_ARGUMENT, ...) is a fault of the program, not of the unit: it
+# propagates under every policy instead of becoming a lost reducer.
+_LOST_STATUSES = ("UNAVAILABLE", "ABORTED", "DEADLINE_EXCEEDED", "CANCELLED",
+                  "DATA_LOSS")
+
+
+def _lost_unit(e: BaseException) -> bool:
+    """True for what a lost unit raises: an ``InjectedFailure`` or a runtime
+    error whose status says the device went away."""
+    return isinstance(e, InjectedFailure) or (
+        isinstance(e, JaxRuntimeError)
+        and str(e).split(":", 1)[0].strip() in _LOST_STATUSES)
 
 
 @dataclasses.dataclass
@@ -117,7 +135,10 @@ class ResiliencePolicy:
     """HOW a run survives faults.  Pass as ``ExecutionSpec(resilience=...)``.
 
     ``on_failure`` is the disposition when a unit of work (a simulated-MR
-    reducer, a streaming chunk, a sharded round, a training step) raises:
+    reducer, a streaming chunk, a sharded round, a training step) is lost —
+    it raises an ``InjectedFailure`` or a runtime error saying its device
+    went away.  Any other error (lowering, compile, out of memory, a bug)
+    propagates under every disposition:
 
     * ``"retry"`` — re-run the unit up to ``max_retries`` times with
       exponential backoff (``backoff_s * 2**attempt`` seconds), then raise.
@@ -247,7 +268,9 @@ def run_resilient(n: int, run_one: Callable[[int], Any],
                 t0 = time.perf_counter()
                 out = run_one(i)
                 dt = time.perf_counter() - t0
-            except Exception as e:
+            except (InjectedFailure, JaxRuntimeError) as e:
+                if not _lost_unit(e):
+                    raise
                 if isinstance(e, InjectedFailure):
                     rep.failures_injected += 1
                     _count("failures_injected")
@@ -295,7 +318,9 @@ def run_unit(run: Callable[[], Any], policy: ResiliencePolicy, *,
             if policy.injector is not None:
                 policy.injector.maybe_fail(point)
             run()
-        except Exception as e:
+        except (InjectedFailure, JaxRuntimeError) as e:
+            if not _lost_unit(e):
+                raise
             if isinstance(e, InjectedFailure):
                 report.failures_injected += 1
                 _count("failures_injected")
@@ -334,7 +359,9 @@ def retry_call(fn: Callable[[], Any], policy: ResiliencePolicy, *,
             if policy.injector is not None:
                 policy.injector.maybe_fail(point)
             return fn(), rep
-        except Exception as e:
+        except (InjectedFailure, JaxRuntimeError) as e:
+            if not _lost_unit(e):
+                raise
             if isinstance(e, InjectedFailure):
                 rep.failures_injected += 1
                 _count("failures_injected")

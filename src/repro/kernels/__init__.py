@@ -1,5 +1,7 @@
-"""Pallas TPU kernels for the core-set hot spots (validated via interpret mode
-on CPU; see tests/test_kernels.py for the shape/dtype sweeps vs ref.py)."""
+"""Pallas TPU kernels for the core-set hot spots: compiled by Mosaic on a
+TPU (``tests/test_tpu_compile.py`` compiles them for a described v5e),
+interpreted on the CPU backend (``tests/test_kernels.py`` sweeps shapes and
+dtypes against ``ref.py``)."""
 from . import ops, ref
 from .gmm_topb import gmm_topb_pallas
 from .gmm_update import (gmm_grouped_topb_pallas, gmm_update_select_pallas,

@@ -1,16 +1,15 @@
-"""Fused batched-GMM round Pallas kernel: distance block + running min +
-per-block TOP-B in a single VMEM pass.
+"""Fused batched-GMM round: distance block + running min in a single VMEM
+pass (the shared fold kernel of ``gmm_update``), then the TOP-P candidates
+of the updated field.
 
-This is the production-TPU form of §Perf iteration 4 (the chunk-fused sweep
-in `core/gmm.gmm_batched(chunk=...)`): per grid step, one (bn, d) point tile
-meets a (b, d) center block on the MXU, the running min-distance update
-happens in registers, and each tile emits its local top-b (value, index)
-pairs.  The cross-tile merge — top-b of (grid·b) candidates — is O(n/bn · b)
-and runs in the jit wrapper.  The (n, b) distance matrix never exists in
-HBM, which is what makes the batched sweep bandwidth-optimal (one point-set
-read per b centers).
-
-Chunk-local top-b followed by a global top-b over tile winners is exact.
+This is the production-TPU form of the chunk-fused sweep in
+``core/gmm.gmm_batched(chunk=...)``: per grid step, one (bn, d) point tile
+meets a (b, d) center block on the MXU and the running min-distance update
+happens in VMEM, so the (n, b) distance matrix never exists in HBM — one
+point-set read per b centers.  Mosaic cannot lower ``lax.top_k``, so the
+top-p runs in the jit wrapper, tile by tile over the (n,) field (4n bytes
+re-read plus the n-byte mask; 1/d of the point sweep) and then merged —
+exact, like the chunk-local top-k of the jnp sweep.
 """
 from __future__ import annotations
 
@@ -18,34 +17,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from .gmm_update import resolve_interpret
-
-
-def _topb_kernel(x_ref, c_ref, xsq_ref, csq_ref, min_ref, mask_ref,
-                 min_out_ref, val_ref, idx_ref, *, mode, bn, p):
-    i = pl.program_id(0)
-    x = x_ref[...]                                   # (bn, d)
-    c = c_ref[...]                                   # (b, d)
-    dot = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    if mode in ("sqeuclidean", "euclidean"):
-        d2 = xsq_ref[...][:, None] + csq_ref[...][None, :] - 2.0 * dot
-        d2 = jnp.maximum(d2, 0.0)
-        dist = jnp.sqrt(d2) if mode == "euclidean" else d2
-    elif mode == "dot":
-        dist = -dot
-    elif mode == "cosine":
-        dist = jnp.arccos(jnp.clip(dot, -1.0, 1.0))
-    else:
-        raise ValueError(mode)
-    new_min = jnp.minimum(min_ref[...], jnp.min(dist, axis=1))
-    min_out_ref[...] = new_min
-    masked = jnp.where(mask_ref[...], new_min, -jnp.inf)
-    vals, idxs = jax.lax.top_k(masked, p)            # tile-local top-p
-    val_ref[...] = vals
-    idx_ref[...] = (idxs + i * bn).astype(jnp.int32)
+from .gmm_update import fold_pallas, top_p
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "bn", "p", "interpret"))
@@ -55,45 +28,17 @@ def gmm_topb_pallas(points, centers, min_in, mask, *, mode: str = "euclidean",
     min_in (n,), mask (n,) -> (min_out (n,), cand_val (p,), cand_idx (p,)).
 
     cand_* are the exact global top-p of the updated masked min-distance
-    field (tile-local top-p + cross-tile merge).  ``p`` defaults to the
-    center-block size b; the adaptive/oversampled engines pass p=2b to pull
-    a candidate pool wider than the block from the same sweep.
-    ``interpret=None`` auto-selects per backend (see
-    ``gmm_update.resolve_interpret``)."""
-    interpret = resolve_interpret(interpret)
-    n, d = points.shape
-    b = centers.shape[0]
-    p = b if p is None else p
-    assert n % bn == 0 and bn >= p, (n, bn, p)
-    xsq = jnp.sum(points * points, axis=-1)
-    csq = jnp.sum(centers * centers, axis=-1)
-    grid = (n // bn,)
-    min_out, vals, idxs = pl.pallas_call(
-        functools.partial(_topb_kernel, mode=mode, bn=bn, p=p),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bn, d), lambda i: (i, 0)),
-            pl.BlockSpec((b, d), lambda i: (0, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((b,), lambda i: (0,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((p,), lambda i: (i,)),
-            pl.BlockSpec((p,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0] * p,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0] * p,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(points, centers, xsq, csq, min_in, mask)
-    # cross-tile merge: top-p of (grid*p) winners — exact global top-p
-    mvals, sel = jax.lax.top_k(vals, p)
-    return min_out, mvals, idxs[sel]
+    field.  ``p`` defaults to the center-block size b; the
+    adaptive/oversampled engines pass p=2b to pull a candidate pool wider
+    than the block from the same sweep.  ``interpret=None`` auto-selects per
+    backend (see ``gmm_update.resolve_interpret``)."""
+    n = points.shape[0]
+    p = centers.shape[0] if p is None else p
+    assert n % bn == 0 and n >= p, (n, bn, p)
+    min_out = fold_pallas(points, centers, min_in, mode=mode, bn=bn,
+                          interpret=interpret)
+    vals, idxs = top_p(jnp.where(mask, min_out, -jnp.inf), p, bn)
+    return min_out, vals, idxs
 
 
 def gmm_topb_ref(points, centers, min_in, mask, mode: str = "euclidean",

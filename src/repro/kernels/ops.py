@@ -1,12 +1,9 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handle padding to tile boundaries, metric-name -> kernel-mode translation
-(cosine pre-normalizes once so the kernel is a pure dot+arccos), and the
-interpret switch: on backends without a Pallas lowering (the CPU test/dev
-container) every kernel runs under ``interpret=True`` (the kernel body
-executed by the Pallas interpreter); on TPU/GPU the same call sites compile
-to Mosaic/Triton.  ``REPRO_PALLAS_INTERPRET=0|1`` overrides (see
-``gmm_update.resolve_interpret``).
+Handle padding to tile boundaries and metric-name -> kernel-mode
+translation (cosine pre-normalizes once so the kernel is a pure
+dot+arccos).  The kernels compile to Mosaic on a TPU and run under the
+Pallas interpreter on the CPU backend (``gmm_update.resolve_interpret``).
 """
 from __future__ import annotations
 
@@ -16,13 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from .gmm_topb import gmm_topb_pallas
-from .gmm_update import (gmm_grouped_topb_pallas, gmm_update_select_pallas,
-                         resolve_interpret)
+from .gmm_update import gmm_grouped_topb_pallas, gmm_update_select_pallas
 from .pairwise import pairwise_pallas
-
-
-def _interpret() -> bool:
-    return resolve_interpret(None)
 
 
 def _round_up(x, m):
@@ -58,8 +50,7 @@ def pairwise(x, y, metric_name: str = "sqeuclidean", bm: int = 256,
     mp, np_ = _round_up(m, bm_), _round_up(n, bn_)
     xp = jnp.pad(x, ((0, mp - m), (0, 0)))
     yp = jnp.pad(y, ((0, np_ - n), (0, 0)))
-    out = pairwise_pallas(xp, yp, mode=mode, bm=bm_, bn=bn_,
-                          interpret=_interpret())
+    out = pairwise_pallas(xp, yp, mode=mode, bm=bm_, bn=bn_)
     return out[:m, :n]
 
 
@@ -83,8 +74,7 @@ def gmm_update_select(points, centers, min_in, mask, metric_name: str,
     mi = jnp.pad(min_in, (0, npad - n), constant_values=jnp.inf)
     mk = jnp.pad(mask, (0, npad - n), constant_values=False)
     min_out, arg, mx = gmm_update_select_pallas(pp, centers, mi, mk,
-                                                mode=mode, bn=bn_,
-                                                interpret=_interpret())
+                                                mode=mode, bn=bn_)
     return min_out[:n], arg, mx
 
 

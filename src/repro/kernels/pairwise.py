@@ -2,9 +2,14 @@
 
 The workhorse of the core-set stack: the SMM chunk filter, the final
 sequential solvers and the measure evaluations all consume an ``(m, n)``
-distance matrix.  On TPU the ``x @ yᵀ`` term is an MXU matmul; the norm
-corrections and the elementwise transform (clamp/sqrt/arccos) are fused into
-the same VMEM tile so the matrix is written to HBM exactly once.
+distance matrix.  On TPU the ``x @ yᵀ`` term is an MXU matmul (at
+``Precision.HIGHEST``: the default rounds f32 through bf16); the norm
+corrections and the elementwise transform (clamp/sqrt) are fused into the
+same VMEM tile so the matrix is written to HBM exactly once (cosine's
+arccos has no Mosaic lowering and runs as one elementwise pass after).  Both
+norms are computed in-kernel — ``‖x‖²`` as a (bm, 1) column, ``‖y‖²`` as a
+row sum transposed to (1, bn) — so no rank-1 block crosses the kernel
+boundary.
 
 Tiling: grid over (m/bm, n/bn); both point tiles keep the full feature dim
 ``d`` resident (embeddings here are 3–8192 wide — at bm=bn=256 and d=1024
@@ -20,26 +25,29 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .gmm_update import _HIGHEST, staged_interpret
 
-def _transform(d2_or_dot, xsq_tile, ysq_tile, mode):
+
+def _transform(dot, x, y, mode):
     if mode in ("sqeuclidean", "euclidean"):
-        d2 = xsq_tile[:, None] + ysq_tile[None, :] - 2.0 * d2_or_dot
-        d2 = jnp.maximum(d2, 0.0)
+        xsq = jnp.sum(x * x, axis=1, keepdims=True)          # (bm, 1)
+        ysq = jnp.sum(y * y, axis=1, keepdims=True).T        # (1, bn)
+        d2 = jnp.maximum(xsq + ysq - 2.0 * dot, 0.0)
         return jnp.sqrt(d2) if mode == "euclidean" else d2
     if mode == "dot":
-        return -d2_or_dot
+        return -dot
     if mode == "cosine":
-        return jnp.arccos(jnp.clip(d2_or_dot, -1.0, 1.0))
+        return dot            # Mosaic has no arccos: applied after the kernel
     raise ValueError(mode)
 
 
-def _pairwise_kernel(x_ref, y_ref, xsq_ref, ysq_ref, o_ref, *, mode):
+def _pairwise_kernel(x_ref, y_ref, o_ref, *, mode):
     x = x_ref[...]                       # (bm, d)
     y = y_ref[...]                       # (bn, d)
     dot = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())),
+        x, y, (((1,), (1,)), ((), ())), precision=_HIGHEST,
         preferred_element_type=jnp.float32)          # (bm, bn) on the MXU
-    o_ref[...] = _transform(dot, xsq_ref[...], ysq_ref[...], mode)
+    o_ref[...] = _transform(dot, x, y, mode)
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "bm", "bn", "interpret"))
@@ -48,24 +56,18 @@ def pairwise_pallas(x, y, *, mode: str = "sqeuclidean", bm: int = 256,
     """Distance matrix via pl.pallas_call.  Inputs must be pre-padded so that
     m % bm == 0 and n % bn == 0 (ops.py handles padding + unpadding).
     ``interpret=None`` auto-selects per backend (``resolve_interpret``)."""
-    from .gmm_update import resolve_interpret
-    interpret = resolve_interpret(interpret)
     m, d = x.shape
     n, _ = y.shape
     assert m % bm == 0 and n % bn == 0, (m, n, bm, bn)
-    xsq = jnp.sum(x * x, axis=-1)
-    ysq = jnp.sum(y * y, axis=-1)
-    grid = (m // bm, n // bn)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_pairwise_kernel, mode=mode),
-        grid=grid,
+        grid=(m // bm, n // bn),
         in_specs=[
             pl.BlockSpec((bm, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bm,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (j,)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
-    )(x, y, xsq, ysq)
+        interpret=staged_interpret(interpret),
+    )(x, y)
+    return jnp.arccos(jnp.clip(out, -1.0, 1.0)) if mode == "cosine" else out

@@ -1,12 +1,17 @@
 """Pure-jnp oracles for the Pallas kernels (the ``ref`` side of every
-kernel-vs-reference allclose test)."""
+kernel-vs-reference allclose test), at full f32 matmul precision."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
 def _sq(x):
     return jnp.sum(x * x, axis=-1)
+
+
+def _mm(x, y):
+    return jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
 
 
 def pairwise_ref(x, y, mode: str = "sqeuclidean"):
@@ -17,13 +22,13 @@ def pairwise_ref(x, y, mode: str = "sqeuclidean"):
     inputs are expected pre-normalized by the ops wrapper).
     """
     if mode in ("sqeuclidean", "euclidean"):
-        d2 = _sq(x)[:, None] + _sq(y)[None, :] - 2.0 * (x @ y.T)
+        d2 = _sq(x)[:, None] + _sq(y)[None, :] - 2.0 * _mm(x, y)
         d2 = jnp.maximum(d2, 0.0)
         return jnp.sqrt(d2) if mode == "euclidean" else d2
     if mode == "dot":
-        return -(x @ y.T)
+        return -_mm(x, y)
     if mode == "cosine":
-        sim = jnp.clip(x @ y.T, -1.0, 1.0)
+        sim = jnp.clip(_mm(x, y), -1.0, 1.0)
         return jnp.arccos(sim)
     raise ValueError(mode)
 

@@ -190,7 +190,7 @@ def lower_paper_cell(mesh, *, n_points: int = 2 ** 30, dim: int = 64,
     Round 1 = per-device GMM on the local shard (shard_map), round 2 = the
     all-gather 'shuffle'.  ``batch_b > 0`` switches round 1 to the batched
     lookahead-b GMM (EXPERIMENTS.md §Perf hillclimb #1)."""
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.gmm import gmm as _gmm, gmm_batched as _gmm_b
 
     daxes = data_axes(mesh)

@@ -84,7 +84,7 @@ def _expert_ffn(buf, w_gate, w_up, w_down, cfg: ModelConfig):
 
 def _moe_mlp_shard_map(x, router_w, w_gate, w_up, w_down, cfg: ModelConfig,
                        rules: ShardingRules, mesh):
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, D = x.shape

@@ -19,8 +19,9 @@ timers.  This module unifies them:
   active these are a single global load + ``is None`` check (no allocation,
   measured by the disabled-mode test), so the engines carry their probes
   permanently at near-zero cost;
-* ``jit_recompiles`` comes from a ``jax.monitoring`` listener counting
-  backend-compile events (installed once, forwards to the active trace).
+* ``jit_recompiles`` (and ``compile_seconds``, the cache hits/misses) come
+  from ``jax.monitoring`` listeners on backend-compile and compile-cache
+  events (installed once, forwarding to the active trace).
 
 ``RunTrace`` is also a ``Mapping`` so the legacy telemetry dict contract
 (``res.telemetry["phases"]`` -> ``[{"name", "seconds"}, ...]``) keeps
@@ -42,11 +43,18 @@ from typing import Any, Dict, List, Optional, Tuple
 #   host_syncs        blocking device->host transfers (each one stalls the
 #                     dispatch pipeline — the pacing metric sprint mode
 #                     collapses from O(k'/b) to O(#segments))
-#   device_dispatches jitted computations launched by a host driver
+#   device_dispatches jitted computations launched by a host loop (the
+#                     simulated MapReduce path launches one per reducer)
 #   pool_widenings    adaptive-controller oversampling-pool doublings
 #   sprint_segments   device-resident adaptive segments (one fused
 #                     while_loop dispatch each; see core.adaptive sprint)
 #   jit_recompiles    backend compiles observed while the trace was active
+#                     (their seconds accumulate in RunTrace.compile_seconds)
+#   compile_cache_hits / compile_cache_misses
+#                     persistent compile-cache lookups that hit / missed
+#   pallas_compiled   Pallas kernels staged for the device compiler (not the
+#                     interpreter); counted at trace time, so a program
+#                     served from the jit cache adds none
 #   points_absorbed   stream points folded into the SMM state
 #   merges            SMM merge/restructure events (threshold doublings)
 #   retries           work units (reducers/chunks/rounds/steps) re-run after
@@ -71,8 +79,9 @@ from typing import Any, Dict, List, Optional, Tuple
 #                     level they construct)
 COUNTER_NAMES = ("distance_evals", "bytes_swept", "host_syncs",
                  "device_dispatches", "pool_widenings", "sprint_segments",
-                 "jit_recompiles", "points_absorbed", "merges", "retries",
-                 "failures_injected", "checkpoints_written",
+                 "jit_recompiles", "compile_cache_hits",
+                 "compile_cache_misses", "pallas_compiled",
+                 "points_absorbed", "merges", "retries", "failures_injected", "checkpoints_written",
                  "reducers_recovered", "sessions_active", "rerank_batched",
                  "coreset_reuses", "inserts_absorbed", "deletes_absorbed",
                  "level_rebuilds")
@@ -88,14 +97,11 @@ def sweep_bytes(n: int, d: int, sweeps: int = 1, m: int = 1) -> int:
 
 
 def _block(x) -> None:
-    """Fence: wait for every jax array in ``x`` (non-array leaves pass)."""
-    if x is None:
-        return
-    try:
+    """Fence: wait for every jax array in ``x`` (non-array leaves pass).  A
+    device error surfacing here propagates."""
+    if x is not None:
         import jax
         jax.block_until_ready(x)
-    except Exception:
-        pass
 
 
 class Span:
@@ -134,14 +140,11 @@ class _SpanCtx:
         self._jax = None
 
     def __enter__(self) -> Span:
-        try:
-            import jax
-            stack = contextlib.ExitStack()
-            stack.enter_context(jax.profiler.TraceAnnotation(self._span.name))
-            stack.enter_context(jax.named_scope(self._span.name))
-            self._jax = stack
-        except Exception:
-            self._jax = None
+        import jax
+        stack = contextlib.ExitStack()
+        stack.enter_context(jax.profiler.TraceAnnotation(self._span.name))
+        stack.enter_context(jax.named_scope(self._span.name))
+        self._jax = stack
         self._trace._push(self._span)
         self._span.t0 = time.perf_counter()
         return self._span
@@ -195,6 +198,7 @@ class RunTrace(Mapping):
         self.counters: Dict[str, int] = collections.Counter()
         self.spans: List[Span] = []
         self.extras: Dict[str, Any] = {}
+        self.compile_seconds = 0.0      # backend compile time while active
         self.t_start = time.perf_counter()
         self._stack: List[Span] = []
 
@@ -347,11 +351,13 @@ def trace_from_spec(knob) -> RunTrace:
 
 
 # --------------------------------------------------------------------------
-# jit-recompile probe (jax.monitoring event listener, installed once)
+# compile probe (jax.monitoring event listeners, installed once)
 # --------------------------------------------------------------------------
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_PROBE = {"state": "pending"}      # pending | installed | unavailable
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "compile_cache_hits",
+                 "/jax/compilation_cache/cache_misses": "compile_cache_misses"}
+_PROBE_INSTALLED = False
 
 
 def _on_compile_event(event, duration=None, **kw):   # pragma: no cover - cb
@@ -360,18 +366,21 @@ def _on_compile_event(event, duration=None, **kw):   # pragma: no cover - cb
     t = _ACTIVE
     if t is not None and t.enabled:
         t.counters["jit_recompiles"] += 1
+        t.compile_seconds += duration or 0.0
 
 
-def _install_recompile_probe() -> bool:
-    """Register the backend-compile listener (idempotent; degrades to a
-    no-op probe on jax versions without ``jax.monitoring``)."""
-    if _PROBE["state"] != "pending":
-        return _PROBE["state"] == "installed"
-    try:
+def _on_cache_event(event, **kw):                    # pragma: no cover - cb
+    t = _ACTIVE
+    if event in _CACHE_EVENTS and t is not None and t.enabled:
+        t.counters[_CACHE_EVENTS[event]] += 1
+
+
+def _install_recompile_probe() -> None:
+    """Register the backend-compile and compile-cache listeners
+    (idempotent)."""
+    global _PROBE_INSTALLED
+    if not _PROBE_INSTALLED:
         import jax.monitoring as jm
         jm.register_event_duration_secs_listener(_on_compile_event)
-        _PROBE["state"] = "installed"
-        return True
-    except Exception:                                # pragma: no cover
-        _PROBE["state"] = "unavailable"
-        return False
+        jm.register_event_listener(_on_cache_event)
+        _PROBE_INSTALLED = True

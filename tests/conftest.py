@@ -1,6 +1,9 @@
-# NOTE: deliberately NO XLA_FLAGS device-count override here — smoke tests and
-# benches must see the real (single) device; only launch/dryrun.py and the
-# explicit subprocess tests fake 512/8 devices.
+# The tests run on the CPU backend (JAX_PLATFORMS=cpu), with the Pallas
+# kernels interpreted.  Deliberately NO XLA_FLAGS device-count override here:
+# tests see the one CPU device; only launch/dryrun.py and the explicit
+# subprocess tests fake 512/8/4 devices.  TPU compiles happen ahead of time
+# against a described topology (tests/test_tpu_compile.py); the chip itself
+# is exercised by chip_smoke.py.
 import importlib.util
 import os
 import sys

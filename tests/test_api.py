@@ -138,6 +138,40 @@ def test_indices_are_lazy_for_matched_paths():
     assert len(set(idx.tolist())) == 4
 
 
+def _match_rows_by_scan(pts, sol, k, row_labels=None, sol_labels=None):
+    """The per-point O(n) nearest-row scan ``_match_rows`` must agree with."""
+    idx, taken = [], np.zeros(len(pts), bool)
+    for t, s in enumerate(sol):
+        d = np.linalg.norm(pts - s[None, :], axis=1)
+        if row_labels is not None:
+            d = np.where(row_labels == sol_labels[t], d, np.inf)
+        d[taken] = np.inf
+        j = int(np.argmin(d))
+        if np.isfinite(d[j]):
+            idx.append(j)
+            taken[j] = True
+    return np.asarray(idx[:k])
+
+
+@pytest.mark.parametrize("labelled", [False, True])
+def test_match_rows_single_pass_equals_scan(labelled):
+    """Duplicate input rows, repeated solution rows, shared first
+    coordinates and a solution point that is no input row."""
+    from repro.data.selection import _match_rows
+
+    rng = np.random.default_rng(3)
+    pts = rng.normal(size=(500, 4)).astype(np.float32)
+    pts[10] = pts[3]                     # duplicate row
+    pts[20:40, 0] = pts[5, 0]            # many rows share a first coordinate
+    lab = rng.integers(0, 3, size=500)
+    sol = np.concatenate([pts[[3, 3, 5, 25, 77]],
+                          pts[[120]] + np.float32(1e-3)])
+    sol_lab = lab[[3, 10, 5, 25, 77, 120]] if labelled else None
+    kw = dict(row_labels=lab, sol_labels=sol_lab) if labelled else {}
+    np.testing.assert_array_equal(_match_rows(pts, sol, 6, **kw),
+                                  _match_rows_by_scan(pts, sol, 6, **kw))
+
+
 # --------------------------------------------------------------------------
 # plan.explain golden
 # --------------------------------------------------------------------------

@@ -161,6 +161,36 @@ def test_mapreduce_counters_and_reducer_spans():
     assert "mr_stragglers" in tr.extras
 
 
+@pytest.mark.parametrize("trace", [True, "reducers"])
+def test_mapreduce_dispatches_one_per_reducer(trace):
+    # the simulated reducers run one dispatch each, fenced or not
+    res = _run(_pts(2048), mode="mapreduce", num_reducers=4, kprime=16,
+               b=1, trace=trace)
+    assert res.telemetry.counters["device_dispatches"] == 4
+
+
+def test_compile_probe_counts_compiles_and_seconds():
+    import jax
+    import jax.numpy as jnp
+
+    tr = RunTrace(enabled=True)
+    with T.activate(tr):
+        jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.0)(jnp.ones((7, 5)))
+    assert tr.counters["jit_recompiles"] >= 1
+    assert tr.compile_seconds > 0.0
+
+
+def test_pallas_compiled_counts_only_compiled_kernels():
+    from repro.kernels.gmm_update import staged_interpret
+
+    tr = RunTrace(enabled=True)
+    with T.activate(tr):
+        assert staged_interpret(True) is True
+        assert tr.counters["pallas_compiled"] == 0
+        assert staged_interpret(False) is False
+    assert tr.counters["pallas_compiled"] == 1
+
+
 def test_streaming_counters_chunk_invariant():
     # the SMM state evolution is a function of the point order, not of how
     # the stream is chunked: work counters and the result must agree.

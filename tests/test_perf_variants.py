@@ -73,7 +73,7 @@ _EF_SUBPROC = textwrap.dedent("""
     import json
     import numpy as np
     import jax, jax.numpy as jnp
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed import psum_bf16, psum_int8_ef, init_error_feedback
 

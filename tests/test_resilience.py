@@ -112,6 +112,35 @@ def test_run_resilient_degrade_collects_survivors():
     assert rep.degraded and rep.to_dict()["degraded"]
 
 
+@pytest.mark.parametrize("on_failure", ["retry", "degrade"])
+def test_program_errors_are_not_lost_units(on_failure):
+    """A compile / memory / program error is not a lost reducer: it
+    propagates under every policy instead of being retried or dropped."""
+    from jax.errors import JaxRuntimeError
+
+    pol = ResiliencePolicy(on_failure=on_failure, max_retries=3)
+    for err in (ValueError("bad lowering"),
+                JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory"),
+                JaxRuntimeError("INTERNAL: Mosaic failed to compile")):
+        def run_one(i, err=err):
+            raise err
+        with pytest.raises(type(err)):
+            run_resilient(2, run_one, pol)
+        with pytest.raises(type(err)):
+            retry_call(lambda: run_one(0), pol, point="round:x")
+    lost = JaxRuntimeError("UNAVAILABLE: device lost")
+    seen = []
+
+    def flaky(i):
+        if not seen:
+            seen.append(i)
+            raise lost
+        return i
+    out, rep = run_resilient(2, flaky, pol)
+    assert rep.degraded == (on_failure == "degrade")
+    assert rep.retries == (on_failure == "retry")
+
+
 def test_retry_call_round_scope():
     attempts = []
     inj = FailureInjector(fail_at=("round:mr.round1",))

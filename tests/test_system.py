@@ -94,8 +94,5 @@ def test_hlo_cost_analyzer_scan_weighting():
     expect = L * 2 * 128 * 256 * 256
     assert rep.flops == pytest.approx(expect, rel=0.02)
     # single-visit XLA count must be ~1/L of ours
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # jax 0.4.x returns [dict]
-        cost = cost[0]
-    xla = cost["flops"]
+    xla = compiled.cost_analysis()["flops"]
     assert rep.flops / max(xla, 1) == pytest.approx(L, rel=0.05)
