@@ -19,32 +19,6 @@ from benchmarks import bench_constrained, bench_mr, bench_streaming
 from benchmarks.common import table
 
 
-def emit_trace_artifact(quick: bool = True,
-                        path: str = "BENCH_trace.json") -> str:
-    """One traced run per execution mode, aggregated into a single Chrome
-    ``trace_event`` artifact (loadable in Perfetto / ``chrome://tracing``).
-    CI uploads it next to the BENCH_*.json rows so a regression in the
-    counter gate can be read straight off the span timeline."""
-    import numpy as np
-
-    import repro
-    from repro.obs import write_chrome_trace
-    from repro.obs.trace import RunTrace
-
-    n = 2 ** 15 if quick else 2 ** 18
-    pts = np.random.default_rng(7).normal(size=(n, 8)).astype(np.float32)
-    tr = RunTrace(enabled=True)      # shared: all three modes in one doc
-    for mode, kw in (("batch", {"kprime": 64, "b": "auto"}),
-                     ("streaming", {"kprime": 64, "chunk": 4096}),
-                     ("mapreduce", {"kprime": 64, "num_reducers": 8})):
-        repro.diversify(pts, k=16, execution=repro.ExecutionSpec(
-            mode=mode, trace=tr, **kw))
-    write_chrome_trace(tr, path)
-    counters = ", ".join(f"{k}={v:,}" for k, v in sorted(tr.counters.items()))
-    print(f"[trace] wrote {path} ({counters})")
-    return path
-
-
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
@@ -164,11 +138,6 @@ def main(argv=None) -> None:
     bench_dynamic.emit_json(rows, path="BENCH_dynamic.json")
     print(table(rows, ["shape", "path", "n", "rounds", "time_s",
                        "radius_ratio_vs_rebuild"], "Dynamic index"))
-
-    print("\n" + "=" * 72)
-    print("Observability — traced representative runs (BENCH_trace.json)")
-    print("=" * 72)
-    emit_trace_artifact(quick=quick)
 
     if not args.skip_roofline and os.path.isdir("results"):
         print("\n" + "=" * 72)

@@ -11,8 +11,11 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs.trace import count as _count
 
 
 class Coreset(NamedTuple):
@@ -97,7 +100,10 @@ def build_coreset(points, k: int, kprime, measure: str, *,
                                 gmm_ext as _gmm_ext, gmm_gen as _gmm_gen)
     from .measures import NEEDS_INJECTIVE
 
+    on_host = not isinstance(points, jax.Array)
     points = jnp.asarray(points)
+    if on_host:
+        _count("h2d_bytes", points.nbytes)
     auto = kprime == "auto" or b == "auto"
     cert = None
     if kprime == "auto":

@@ -62,6 +62,16 @@ def _pairwise(metric_name, a, b):
     return get_metric(metric_name).pairwise(a, b)
 
 
+def _readback(*xs):
+    """Read device scalars ``xs`` to the host as Python numbers: one
+    blocking transfer, spanned as ``smm.readback`` and counted in
+    ``host_syncs`` (one value, or a list of them)."""
+    with _span("smm.readback"):
+        out = [np.asarray(x).item() for x in jax.device_get(xs)]
+    _count("host_syncs")
+    return out[0] if len(out) == 1 else out
+
+
 @functools.partial(jax.jit, static_argnames=("metric_name",))
 def _init_threshold(T, metric_name):
     dm = _pairwise(metric_name, T, T)
@@ -303,7 +313,7 @@ class StreamingCoreset:
         self._n_processed = self.cap
         cap, k, dim = self.cap, self.k, self.dim
         k_slots = k if self.mode == "ext" else 1
-        T = jnp.asarray(pts0, self.dtype)
+        T = self._upload(pts0)
         e_pts = jnp.zeros((cap, k_slots, dim), self.dtype)
         if self.mode == "ext":
             e_pts = e_pts.at[:, 0].set(T)
@@ -330,20 +340,26 @@ class StreamingCoreset:
             # if the MIS removed nothing (all pairwise > 2 d_i) the update
             # step is empty: double the threshold and merge again (see
             # module docstring).
-            while int(jnp.sum(state.t_valid)) >= self.cap:
-                _count("host_syncs")
+            while _readback(jnp.sum(state.t_valid)) >= self.cap:
                 state = state._replace(d_thr=state.d_thr * 2.0)
                 state = _merge(state, self.metric, self.mode, self.k)
                 _count("device_dispatches")
-            _count("host_syncs")                 # the loop-exit readback
             _count("merges")
             # stamp with the exact number of stream points processed when the
             # merge fired (NOT n_seen, which already counts the whole
             # in-flight chunk) — this keeps the re-certification log
             # chunk-invariant.
-            self._phase_log.append((self._n_processed, float(state.d_thr)))
-            _count("host_syncs")                 # d_thr stamp readback
+            self._phase_log.append((self._n_processed,
+                                    _readback(state.d_thr)))
         return state
+
+    def _upload(self, rows):
+        """Put host ``rows`` on the device: spanned as ``smm.upload`` and
+        counted in ``h2d_bytes``."""
+        with _span("smm.upload"):
+            out = jnp.asarray(rows, self.dtype)
+        _count("h2d_bytes", out.nbytes)
+        return out
 
     # -- streaming ----------------------------------------------------------
     def update(self, chunk) -> None:
@@ -352,26 +368,27 @@ class StreamingCoreset:
         chunk = np.atleast_2d(chunk)
         if chunk.shape[0] == 0:
             return
-        self.n_seen += chunk.shape[0]
-        gen0 = self.generation
-        if self._state is None:
-            need = self.cap - sum(len(p) for p in self._prefix)
-            self._prefix.append(chunk[:need])
-            chunk = chunk[need:]
-            if sum(len(p) for p in self._prefix) >= self.cap:
-                self._boot(np.concatenate(self._prefix, axis=0))
-                self._prefix = []
-            else:
-                # still buffering: finalize() would return the grown prefix
+        with _span("smm.update", n=chunk.shape[0]):
+            self.n_seen += chunk.shape[0]
+            gen0 = self.generation
+            if self._state is None:
+                need = self.cap - sum(len(p) for p in self._prefix)
+                self._prefix.append(chunk[:need])
+                chunk = chunk[need:]
+                if sum(len(p) for p in self._prefix) >= self.cap:
+                    self._boot(np.concatenate(self._prefix, axis=0))
+                    self._prefix = []
+                else:
+                    # still buffering: finalize() would return the grown
+                    # prefix
+                    self.generation += 1
+                if chunk.shape[0] == 0:
+                    return
+            self._consume(self._upload(chunk), self.n_seen - chunk.shape[0])
+            if self.mode != "plain" and self.generation == gen0:
+                # ext/gen: even fully-absorbed points mutate delegate sets /
+                # multiplicities, so the finalized core-set may change
                 self.generation += 1
-            if chunk.shape[0] == 0:
-                return
-        self._consume(jnp.asarray(chunk, self.dtype),
-                      self.n_seen - chunk.shape[0])
-        if self.mode != "plain" and self.generation == gen0:
-            # ext/gen: even fully-absorbed points mutate delegate sets /
-            # multiplicities, so the finalized core-set may change
-            self.generation += 1
 
     def _consume(self, chunk, base: int = 0) -> None:
         """Sync-free chunk loop: ``_classify_absorb`` classifies the tail,
@@ -386,23 +403,25 @@ class StreamingCoreset:
         pos = 0
         state = self._state
         while pos < c:
-            tail = chunk[pos:]
-            state, first_far = _classify_absorb(state, tail, self.metric,
-                                                self.mode, self.k)
-            first_far = int(first_far)          # the one host transfer
+            with _span("smm.classify"):
+                tail = chunk[pos:]
+                state, first_far = _classify_absorb(state, tail, self.metric,
+                                                    self.mode, self.k)
             _count("device_dispatches")
-            _count("host_syncs")
+            first_far = _readback(first_far)    # the one host transfer
             if first_far == tail.shape[0]:      # whole tail absorbed
                 pos = c
                 break
             self.generation += 1                # far insert mutates T
-            cvalid = jnp.ones((tail.shape[0],), bool)
-            state, consumed, full = _seq_insert(state, tail, cvalid, first_far,
-                                                self.metric, self.mode, self.k)
-            pos += int(consumed)
+            with _span("smm.insert"):
+                cvalid = jnp.ones((tail.shape[0],), bool)
+                state, consumed, full = _seq_insert(
+                    state, tail, cvalid, first_far, self.metric, self.mode,
+                    self.k)
             _count("device_dispatches")
-            _count("host_syncs")    # consumed+full: one dispatch, one barrier
-            if bool(full):
+            consumed, full = _readback(consumed, full)   # one barrier
+            pos += consumed
+            if full:
                 state = state._replace(d_thr=state.d_thr * 2.0)
                 self._n_processed = base + pos
                 state = self._merge_until_room(state)

@@ -14,11 +14,17 @@ timers.  This module unifies them:
   ``jax.block_until_ready`` so spans measure execution, not async dispatch,
   and enabled spans emit ``jax.profiler.TraceAnnotation`` +
   ``jax.named_scope`` so they line up with device profiles;
+* every span is written into the JAX profiler's trace as ``repro.<name>``
+  whenever a profiler session runs, enabled trace or not.  With no enabled
+  trace it is a bare ``TraceAnnotation``: no fence, no ``Span`` record, so
+  a profile of an untraced run shows the program's own host steps on the
+  device's clock without moving them.  Only an enabled trace ever fences;
 * instrumented call-sites talk to the *active* trace through module-level
   ``count()`` / ``span()`` / ``counting()`` — when no enabled trace is
-  active these are a single global load + ``is None`` check (no allocation,
-  measured by the disabled-mode test), so the engines carry their probes
-  permanently at near-zero cost;
+  active and no profiler runs these are a global load, an ``is None`` check
+  and (``span()``) one ``TraceAnnotation.is_enabled()`` call (no
+  allocation, measured by the disabled-mode test), so the engines carry
+  their probes permanently at near-zero cost;
 * ``jit_recompiles`` (and ``compile_seconds``, the cache hits/misses) come
   from ``jax.monitoring`` listeners on backend-compile and compile-cache
   events (installed once, forwarding to the active trace).
@@ -36,6 +42,8 @@ import time
 from collections.abc import Mapping
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 # Counter glossary (see docs/architecture.md "Observability"):
 #   distance_evals    point-to-center distance evaluations (n x centers folded)
 #   bytes_swept       modeled HBM traffic of the field sweeps (fp32 model
@@ -43,6 +51,10 @@ from typing import Any, Dict, List, Optional, Tuple
 #   host_syncs        blocking device->host transfers (each one stalls the
 #                     dispatch pipeline — the pacing metric sprint mode
 #                     collapses from O(k'/b) to O(#segments))
+#   h2d_bytes         bytes of the host arrays the program puts on the
+#                     device (stream chunks, the boot prefix, a host batch
+#                     input); Python scalars passed as jit arguments are not
+#                     counted
 #   device_dispatches jitted computations launched by a host loop (the
 #                     simulated MapReduce path launches one per reducer)
 #   pool_widenings    adaptive-controller oversampling-pool doublings
@@ -77,7 +89,7 @@ from typing import Any, Dict, List, Optional, Tuple
 #   level_rebuilds    dynamic-index levels (re)built from scratch (boot and
 #                     every RebuildPolicy-triggered rebuild count each
 #                     level they construct)
-COUNTER_NAMES = ("distance_evals", "bytes_swept", "host_syncs",
+COUNTER_NAMES = ("distance_evals", "bytes_swept", "host_syncs", "h2d_bytes",
                  "device_dispatches", "pool_widenings", "sprint_segments",
                  "jit_recompiles", "compile_cache_hits",
                  "compile_cache_misses", "pallas_compiled",
@@ -87,6 +99,7 @@ COUNTER_NAMES = ("distance_evals", "bytes_swept", "host_syncs",
                  "level_rebuilds")
 
 ENV_VAR = "REPRO_TRACE"
+PROFILE_PREFIX = "repro."     # every span's name in a profiler trace
 
 
 def sweep_bytes(n: int, d: int, sweeps: int = 1, m: int = 1) -> int:
@@ -142,7 +155,7 @@ class _SpanCtx:
     def __enter__(self) -> Span:
         import jax
         stack = contextlib.ExitStack()
-        stack.enter_context(jax.profiler.TraceAnnotation(self._span.name))
+        stack.enter_context(_Annotation(PROFILE_PREFIX + self._span.name))
         stack.enter_context(jax.named_scope(self._span.name))
         self._jax = stack
         self._trace._push(self._span)
@@ -304,12 +317,15 @@ def count(name: str, n: int = 1) -> None:
 
 
 def span(name: str, sync=None, **attrs):
-    """Open a nested span on the active trace (no-op context manager when
-    tracing is disabled)."""
+    """Open a nested span on the active trace.  With tracing disabled it is
+    an unfenced profiler annotation ``repro.<name>`` while a profiler
+    session runs, else a no-op context manager."""
     t = _ACTIVE
-    if t is None or not t.enabled:
-        return _NULL_SPAN
-    return _SpanCtx(t, name, sync, attrs or None)
+    if t is not None and t.enabled:
+        return _SpanCtx(t, name, sync, attrs or None)
+    if _Annotation.is_enabled():
+        return _Annotation(PROFILE_PREFIX + name, **attrs)
+    return _NULL_SPAN
 
 
 def reducer_detail() -> bool:

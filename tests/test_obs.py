@@ -197,12 +197,97 @@ def test_streaming_counters_chunk_invariant():
     pts = _pts(4096)
     runs = {c: _run(pts, mode="streaming", kprime=32, chunk=c)
             for c in (256, 1024)}
-    invariant = ("distance_evals", "bytes_swept", "points_absorbed", "merges")
+    invariant = ("distance_evals", "bytes_swept", "points_absorbed", "merges",
+                 "h2d_bytes")
     a, b = (runs[c].telemetry.counters for c in (256, 1024))
     for key in invariant:
         assert a[key] == b[key], key
     assert a["points_absorbed"] == pts.shape[0]
+    assert a["h2d_bytes"] == pts.size * 4           # n * d * 4, every row once
     assert runs[256].value == runs[1024].value
+
+
+def _growing(n=2048, d=8):
+    # a stream whose spread keeps growing: far points, sequential inserts
+    # and threshold doublings all through it
+    rng = np.random.default_rng(0)
+    return (rng.normal(size=(n, d))
+            * np.geomspace(1, 1e4, n)[:, None]).astype(np.float32)
+
+
+@pytest.mark.parametrize("chunk,syncs", [(256, 65), (512, 59)])
+def test_streaming_host_syncs_pinned(chunk, syncs):
+    # every blocking read of the stream counts once: the counts the
+    # program read before its readbacks were spanned
+    c = _run(_growing(), mode="streaming", kprime=16,
+             chunk=chunk).telemetry.counters
+    assert c["merges"] == 14
+    assert c["host_syncs"] == syncs
+
+
+def test_batch_h2d_bytes_counts_the_host_input():
+    pts = _pts(2048)
+    c = _run(pts, kprime=32, b=1).telemetry.counters
+    assert c["h2d_bytes"] == pts.nbytes
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a CPU profiler session; the host annotations whose
+    name starts with ``repro.``, as (name, start_ns, duration_ns)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    events = [(e.name, e.start_ns, e.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith(T.PROFILE_PREFIX)]
+    return out, events
+
+
+def test_profiler_session_gets_unfenced_program_spans(tmp_path,
+                                                      monkeypatch):
+    # tracing off, a profiler on: every span is a bare repro.* annotation.
+    # No enabled span exists to fence (the facade's phase rows still fence
+    # through RunTrace.phase, as they always do).
+    def fenced(*a, **k):
+        raise AssertionError("an enabled span was opened")
+
+    monkeypatch.setattr(T, "_SpanCtx", fenced)
+    pts = _growing()
+    res, events = _profiled(tmp_path, lambda: _run(
+        pts, mode="streaming", kprime=16, chunk=512, trace=False))
+    names = [n for n, _, _ in events]
+    assert names.count("repro.smm.update") == pts.shape[0] // 512
+    for name in ("upload", "classify", "insert", "readback", "merge"):
+        assert f"repro.smm.{name}" in names, name
+    assert "counters" not in dict(res.telemetry)
+    assert T.span("smm.update") is T._NULL_SPAN       # session over
+
+
+def test_enabled_span_is_prefixed_in_profile(tmp_path):
+    # an enabled trace keeps the bare name and writes repro.<name>
+    tr = RunTrace(enabled=True)
+
+    def traced():
+        with T.activate(tr):
+            with T.span("smm.update", n=3):
+                pass
+
+    _, events = _profiled(tmp_path, traced)
+    assert [n for n, _, _ in events] == ["repro.smm.update"]
+    assert [s.name for s in tr.spans] == ["smm.update"]
 
 
 def test_legacy_telemetry_dict_view():
