@@ -20,11 +20,13 @@ The doubling algorithm of Charikar et al. adapted per the paper:
 TPU/throughput adaptation (DESIGN.md §2): the stream is consumed in chunks; a
 single ``(chunk, |T|)`` distance matmul classifies every point, the common-case
 "all discarded" path is fully vectorized (including the capacity-respecting
-delegate scatter), and only points beyond ``4 d_i`` — at most ``k'+1`` per
-phase — fall back to an in-jit sequential insert loop.  This is an exact
-execution of the per-point algorithm (discard decisions are order-independent
-within a chunk because ``T`` only changes when a far point is inserted, and the
-sequential path takes over from the first far point onward).
+delegate scatter), and from the first point beyond ``4 d_i`` on, an in-jit
+loop (``_seq_insert``) visits only the points beyond ``4 d_i`` of the centres
+it started with — the only ones whose answer can change — and inserts those
+still far.  This is an exact execution of the per-point algorithm (discard
+decisions are order-independent within a chunk because ``T`` only grows
+between merges, and a near point's delegate goes to its nearest centre among
+those valid at its own position).
 
 The chunk loop is sync-free in the common case: classification, the on-device
 first-far-position search and the near-prefix absorb are fused into one
@@ -60,6 +62,28 @@ class SMMState(NamedTuple):
 
 def _pairwise(metric_name, a, b):
     return get_metric(metric_name).pairwise(a, b)
+
+
+# Rows per distance block in ``_seq_insert``.  A TPU program's code is held
+# in device memory, and a distance matmul's code grows with its rows: one
+# over the whole chunk added 1.3 MB to each compiled tail length, 16 rows add
+# ~0.1 MB, for ~256 short loop steps a 4096-row chunk.
+_BLOCK = 16
+
+
+def _by_blocks(fn, chunk, dtype):
+    """``fn(rows, at)`` -> one value per row of ``chunk[at:at + len(rows)]``,
+    over blocks of at most ``_BLOCK`` rows (the last one overlapping its
+    predecessor), gathered into a ``(len(chunk),)`` array."""
+    c = chunk.shape[0]
+    b = min(_BLOCK, c)
+
+    def body(i, out):
+        at = jnp.minimum(i * b, c - b)
+        rows = jax.lax.dynamic_slice_in_dim(chunk, at, b)
+        return jax.lax.dynamic_update_slice_in_dim(out, fn(rows, at), at, 0)
+
+    return jax.lax.fori_loop(0, -(-c // b), body, jnp.zeros((c,), dtype))
 
 
 def _readback(*xs):
@@ -206,25 +230,48 @@ def _seq_insert(state: SMMState, chunk, cvalid, start, metric_name: str,
                 mode: str, k: int):
     """Sequential per-point processing from ``start``; stops when T fills.
 
-    Returns (state, next_pos, became_full).
+    Only the rows whose answer can change are visited.  Centres are only
+    added between merges, so a row within ``4 d_i`` of the call's starting
+    centres stays near for the whole call: one ``(chunk, cap)`` distance
+    pass (``_by_blocks``) finds the candidates (rows beyond ``4 d_i`` of
+    every starting centre), and the loop visits them in stream order,
+    inserting each one still far from every centre (the new ones included)
+    in the first free slot.  In ext/gen the near rows of ``[start, next_pos)`` are then
+    absorbed at once, each to its nearest centre among those valid at its
+    own position (lowest slot on ties), with the capacity-respecting,
+    position-ordered adds of ``_absorb_near_prefix``.
+
+    Returns (state, next_pos, became_full, steps), ``steps`` the rows the
+    loop visited.
     """
     cap = state.T.shape[0]
     c = chunk.shape[0]
     metric = get_metric(metric_name)
+    pos = jnp.arange(c)
+    thr = 4.0 * state.d_thr
+    valid0 = state.t_valid
+    live = cvalid & (pos >= start)
+    T0 = state.T
+
+    def far(rows, at):
+        dm = jnp.where(valid0[None, :], _pairwise(metric_name, rows, T0),
+                       jnp.inf)
+        return jnp.min(dm, axis=1) > thr
+
+    cand = live & _by_blocks(far, chunk, bool)
 
     def cond(carry):
-        state, pos, full = carry
-        return (pos < c) & ~full
+        state, cand, r, i, full, filled_at, inserted = carry
+        return jnp.any(cand) & ~full
 
     def body(carry):
-        state, pos, full = carry
-        p = chunk[pos]
-        ok = cvalid[pos]
+        state, cand, r, i, full, filled_at, inserted = carry
+        r = jnp.argmax(cand).astype(jnp.int32)           # first unvisited
+        cand = cand.at[r].set(False)
+        p = chunk[r]
         d = metric.point_to_set(state.T, p)
         d = jnp.where(state.t_valid, d, jnp.inf)
-        nd = jnp.min(d)
-        nst = jnp.argmin(d)
-        is_far = ok & (nd > 4.0 * state.d_thr)
+        is_far = jnp.min(d) > thr
 
         # --- far: insert as a new center in the first invalid slot
         free = jnp.argmin(state.t_valid)                 # first False
@@ -237,21 +284,33 @@ def _seq_insert(state: SMMState, chunk, cvalid, start, metric_name: str,
             if mode == "ext":
                 e_pts = e_pts.at[free, 0].set(jnp.where(is_far, p, e_pts[free, 0]))
             e_cnt = e_cnt.at[free].set(jnp.where(is_far, 1, e_cnt[free]))
-            # --- near: delegate add if room
-            room = e_cnt[nst] < k
-            do_add = ok & ~is_far & room
-            if mode == "ext":
-                e_pts = e_pts.at[nst, jnp.clip(e_cnt[nst], 0, e_pts.shape[1] - 1)].set(
-                    jnp.where(do_add, p, e_pts[nst, jnp.clip(e_cnt[nst], 0,
-                                                             e_pts.shape[1] - 1)]))
-            e_cnt = e_cnt.at[nst].add(jnp.where(do_add, 1, 0))
+        filled_at = filled_at.at[free].set(jnp.where(is_far, r,
+                                                     filled_at[free]))
+        inserted = inserted.at[r].set(is_far)
         new_state = state._replace(T=T, t_valid=t_valid, e_pts=e_pts, e_cnt=e_cnt)
         full = jnp.sum(t_valid) >= cap
-        return new_state, pos + 1, full
+        return new_state, cand, r, i + 1, full, filled_at, inserted
 
-    state, next_pos, full = jax.lax.while_loop(
-        cond, body, (state, jnp.asarray(start, jnp.int32), jnp.asarray(False)))
-    return state, next_pos, full
+    state, _, r, steps, full, filled_at, inserted = jax.lax.while_loop(
+        cond, body, (state, cand, jnp.asarray(0, jnp.int32),
+                     jnp.asarray(0, jnp.int32), jnp.asarray(False),
+                     jnp.full((cap,), c, jnp.int32), jnp.zeros((c,), bool)))
+    # T filled at the last row visited: the caller merges, then goes on
+    next_pos = jnp.where(full, r + 1, c).astype(jnp.int32)
+    if mode in ("ext", "gen"):
+        T = state.T
+
+        def nearest(rows, at):
+            # a slot is seen from the rows after the one that filled it
+            at = at + jnp.arange(rows.shape[0])
+            seen = valid0[None, :] | (filled_at[None, :] < at[:, None])
+            dm = jnp.where(seen, _pairwise(metric_name, rows, T), jnp.inf)
+            return jnp.argmin(dm, axis=1).astype(jnp.int32)
+
+        state = _absorb_near_prefix(state, chunk, live,
+                                    _by_blocks(nearest, chunk, jnp.int32),
+                                    inserted, next_pos, metric_name, mode, k)
+    return state, next_pos, full, steps
 
 
 class StreamingCoreset:
@@ -415,11 +474,12 @@ class StreamingCoreset:
             self.generation += 1                # far insert mutates T
             with _span("smm.insert"):
                 cvalid = jnp.ones((tail.shape[0],), bool)
-                state, consumed, full = _seq_insert(
+                state, consumed, full, steps = _seq_insert(
                     state, tail, cvalid, first_far, self.metric, self.mode,
                     self.k)
             _count("device_dispatches")
-            consumed, full = _readback(consumed, full)   # one barrier
+            consumed, full, steps = _readback(consumed, full, steps)
+            _count("insert_steps", steps)
             pos += consumed
             if full:
                 state = state._replace(d_thr=state.d_thr * 2.0)

@@ -215,14 +215,40 @@ def _growing(n=2048, d=8):
             * np.geomspace(1, 1e4, n)[:, None]).astype(np.float32)
 
 
-@pytest.mark.parametrize("chunk,syncs", [(256, 65), (512, 59)])
-def test_streaming_host_syncs_pinned(chunk, syncs):
+@pytest.mark.parametrize("chunk,syncs,steps", [(256, 65, 114),
+                                               (512, 59, 116)])
+def test_streaming_host_syncs_pinned(chunk, syncs, steps):
     # every blocking read of the stream counts once: the counts the
-    # program read before its readbacks were spanned
+    # program read before its readbacks were spanned.  The insert loop
+    # visits only the rows far from the centres its call started with, and
+    # its count rides on the insert's one read-back.
     c = _run(_growing(), mode="streaming", kprime=16,
              chunk=chunk).telemetry.counters
     assert c["merges"] == 14
     assert c["host_syncs"] == syncs
+    assert c["insert_steps"] == steps
+
+
+@pytest.mark.parametrize("far", [[(0, 100)],
+                                 [(0, 100), (0, 101), (0, 200), (0, 300)]],
+                         ids=["one", "four"])
+def test_insert_steps_counts_rows_far_from_start(far):
+    from repro.core import StreamingCoreset
+
+    # boot: (0..8, 0) merges to centres (0,0), (3,0), (6,0) at d_1 = 1;
+    # every other row lies within 4 d_1 of one of them.  (0,101) is far
+    # from those but near (0,100): visited, not inserted.
+    smm = StreamingCoreset(k=2, kprime=8, dim=2)
+    smm.update(np.asarray([(i, 0) for i in range(9)], np.float32))
+    chunk = np.asarray([(i % 9 + 0.5, 0.5) for i in range(40)], np.float32)
+    chunk[[5, 13, 21, 30][:len(far)]] = far
+    tr = RunTrace(enabled=True)
+    with T.activate(tr):
+        smm.update(chunk)
+    assert tr.counters["insert_steps"] == len(far)
+    assert tr.counters["host_syncs"] == 2      # classify, insert; no merge
+    assert int(np.asarray(smm.state.t_valid).sum()) == 3 + len(
+        {round(y, -2) for _, y in far})
 
 
 def test_batch_h2d_bytes_counts_the_host_input():
